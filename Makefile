@@ -40,7 +40,7 @@ test-faults:
 # split invariance, five-engine DP parity, coordinator (ε, δ) metrics
 # (CI job: test-privacy, run with REPRO_REQUIRE_HYPOTHESIS=1)
 test-privacy:
-	$(PY) -m pytest -x -q tests/test_privacy.py tests/test_compat.py
+	$(PY) -m pytest -x -q tests/test_privacy.py
 
 # multi-device tier: 8 fake CPU devices so the pod client mesh axis and
 # the shard_map seed mesh genuinely partition (CI job: test-multidevice)

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.backend import pallas_interpret, resolve_backend
+from repro.core.backend import resolve_backend
 from repro.core.packing import pack_rows, unpack_rows
 from repro.kernels.mask_uplink import ops as mops
 
@@ -119,7 +119,6 @@ def _staged_fns(mode: str, P: int):
 def uplink_rows(K: int, P: int) -> List[Dict]:
     backend = resolve_backend(None)
     use_pallas = backend == "pallas"
-    interp = pallas_interpret()
     u, n, r, w = _operands(K, P)
     rows = []
     for mode in ("binary", "signed"):
@@ -133,8 +132,7 @@ def uplink_rows(K: int, P: int) -> List[Dict]:
             return words, c, s
 
         fused_fn = jax.jit(lambda u, n, r, w: mops.mask_uplink_fused(
-            u, n, r, None, None, w, mode=mode, use_pallas=use_pallas,
-            interpret=interp))
+            u, n, r, None, None, w, mode=mode, use_pallas=use_pallas))
 
         def fused():
             return fused_fn(u, n, r, w)
@@ -186,7 +184,6 @@ def apply_rows(K: int, P: int) -> List[Dict]:
     """Server side: words → counts → global-model update."""
     backend = resolve_backend(None)
     use_pallas = backend == "pallas"
-    interp = pallas_interpret()
     u, n, r, _ = _operands(K, P)
     m = (r < jnp.clip(u / jnp.where(jnp.abs(n) < _EPS, _EPS, n), 0, 1))
     words = jax.jit(lambda m: pack_rows(m.astype(jnp.int8),
@@ -202,8 +199,7 @@ def apply_rows(K: int, P: int) -> List[Dict]:
         return apply_(unpack(words))
 
     fused_fn = jax.jit(lambda ws: mops.unpack_counts_apply(
-        ws, n[0], base, scale, 1.0, 0.0, use_pallas=use_pallas,
-        interpret=interp))
+        ws, n[0], base, scale, 1.0, 0.0, use_pallas=use_pallas))
 
     def fused():
         return fused_fn(words)

@@ -11,6 +11,10 @@ tracked across commits instead of living only in stdout.  On every
 invocation the harness checks the tracked BENCH files' recorded commits
 against HEAD and warns about any that is NOT an ancestor (i.e. the
 numbers predate a rebase/amend and no longer belong to this history).
+A section that raises prints ``<name>/ERROR`` and its traceback, and the
+harness then exits non-zero.  Compiled programs are cached in
+``JAX_COMPILATION_CACHE_DIR`` when it is set, else in ``.jax_cache/`` at
+the repo root.
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [--quick] [--only NAME]
 """
@@ -20,6 +24,10 @@ import json
 import os
 import subprocess
 import sys
+import traceback
+
+ROOT = os.path.normpath(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 
 def _warn_stale_bench_files() -> None:
@@ -27,8 +35,7 @@ def _warn_stale_bench_files() -> None:
     of HEAD — its numbers were produced on a line of history this
     checkout does not contain (rebase/amend), so the bench trajectory
     has a hole until the section is re-run."""
-    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-    for path in sorted(glob.glob(os.path.join(root, "BENCH_*.json"))):
+    for path in sorted(glob.glob(os.path.join(ROOT, "BENCH_*.json"))):
         try:
             commit = json.load(open(path)).get("commit", "unknown")
         except (OSError, ValueError):
@@ -38,7 +45,7 @@ def _warn_stale_bench_files() -> None:
         try:
             ok = subprocess.run(
                 ["git", "merge-base", "--is-ancestor", commit, "HEAD"],
-                cwd=root, capture_output=True).returncode == 0
+                cwd=ROOT, capture_output=True).returncode == 0
         except OSError:       # no git binary
             return
         if not ok:
@@ -57,6 +64,10 @@ def main() -> None:
     args = ap.parse_args()
 
     _warn_stale_bench_files()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(ROOT, ".jax_cache"))
 
     from . import (engine_bench, fl_suite, kernel_bench, privacy_bench,
                    roofline_report, scale_bench, service_bench)
@@ -83,6 +94,7 @@ def main() -> None:
         sections = {args.only: sections[args.only]}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in sections.items():
         try:
             rows = fn()
@@ -111,8 +123,12 @@ def main() -> None:
                 path = privacy_bench.write_bench_json(rows,
                                                       quick=args.quick)
                 print(f"# wrote {path}", file=sys.stderr)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest
+            traceback.print_exc()
             print(f"{name}/ERROR,0.0,{type(e).__name__}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

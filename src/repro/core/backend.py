@@ -2,10 +2,9 @@
 
 ``core.masking`` and ``core.packing`` accept ``backend="ref" | "pallas" |
 None``.  ``None`` auto-selects: the fused Pallas kernels on TPU, the pure
-jnp reference elsewhere (Pallas interpret mode is correct on CPU but runs
-the kernel body through the interpreter — fine for validation, wrong as a
-default).  Explicit ``backend="pallas"`` off-TPU transparently enables
-interpret mode, which is what the bitwise ref-vs-pallas tests rely on.
+jnp reference elsewhere.  ``backend="pallas"`` on a CPU host runs the
+kernels in interpret mode, which is what the bitwise ref-vs-pallas tests
+rely on; :func:`pallas_interpret` is the one place that decides it.
 
 Override order (most local wins): explicit argument > ``use_backend()``
 context > ``REPRO_BACKEND`` env var > platform auto-detect.
@@ -44,8 +43,19 @@ def resolve_backend(backend: str | None) -> str:
 
 
 def pallas_interpret() -> bool:
-    """Whether pallas_call must run in interpret mode (non-TPU hosts)."""
-    return jax.default_backend() != "tpu"
+    """Whether ``pallas_call`` runs in interpret mode: only on a CPU host.
+
+    On a TPU the kernels are compiled by Mosaic; any other platform has
+    no Pallas path here and raises instead of interpreting silently.
+    """
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas kernel path for platform {platform!r}; run on a TPU, "
+        "or with backend='ref'")
 
 
 @contextmanager

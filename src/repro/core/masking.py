@@ -19,7 +19,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .backend import pallas_interpret, resolve_backend
+from .backend import resolve_backend
 
 Pytree = Any
 _EPS = 1e-30
@@ -176,10 +176,8 @@ def tree_psm(u: Pytree, n: Pytree, key, *, progress, mode="binary",
     backend = resolve_backend(backend)
     if backend == "pallas":
         from ..kernels.psm_mask.ops import psm_ste
-        interp = pallas_interpret()
         return _tree_keyed_map(
-            lambda ul, nl, k: psm_ste(ul, nl, k, progress, mode=mode,
-                                      interpret=interp),
+            lambda ul, nl, k: psm_ste(ul, nl, k, progress, mode=mode),
             key, u, n,
         )
     return _tree_keyed_map(
@@ -238,7 +236,7 @@ def tree_mask_uplink(u: Pytree, n: Pytree, keys, weights, *, mode="binary",
     ``probs=True`` treats ``u`` as Bernoulli probabilities directly
     (FedPM; ``n`` ignored) and matches :func:`tree_bernoulli_stacked`.
     ``backend="pallas"`` runs the fused Pallas kernel (interpret mode
-    off-TPU); ``"ref"`` the single-program jnp oracle — neither ever
+    on a CPU host); ``"ref"`` the single-program jnp oracle — neither ever
     materializes the f32 mask tree or an unpacked bit tensor.
     """
     from ..kernels.mask_uplink.ops import mask_uplink_fused
@@ -263,8 +261,7 @@ def tree_mask_uplink(u: Pytree, n: Pytree, keys, weights, *, mode="binary",
     out = mask_uplink_fused(uf, nf, rf, None, None, weights,
                             mode=("prob" if probs else mode),
                             wsum_values=wsum_values,
-                            use_pallas=(backend == "pallas"),
-                            interpret=pallas_interpret())
+                            use_pallas=(backend == "pallas"))
     return TreeUplink(out.words, out.counts, out.wsum)
 
 

@@ -17,7 +17,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from .backend import pallas_interpret, resolve_backend
+from .backend import resolve_backend
 
 Pytree = Any
 WORD = 32
@@ -123,7 +123,7 @@ def pack_rows(bits: jax.Array, *, backend: str | None = None) -> jax.Array:
         x = bits.astype(jnp.int8)
         if pad:
             x = jnp.pad(x, [(0, 0), (0, pad)])
-        return pack(x, use_pallas=True, interpret=pallas_interpret())
+        return pack(x, use_pallas=True)
     return pack_lastdim(bits)
 
 
@@ -133,7 +133,7 @@ def unpack_rows(words: jax.Array, n_bits: int,
     backend = resolve_backend(backend)
     if backend == "pallas":
         from ..kernels.bitpack.ops import unpack
-        bits = unpack(words, use_pallas=True, interpret=pallas_interpret())
+        bits = unpack(words, use_pallas=True)
         return bits[..., :n_bits]
     return unpack_lastdim(words, n_bits)
 
@@ -217,8 +217,7 @@ def tree_unpack_counts(words: jax.Array, like: Pytree, *,
     backend = resolve_backend(backend)
     if backend == "pallas":
         from ..kernels.mask_uplink.ops import unpack_counts
-        c = unpack_counts(words, use_pallas=True,
-                          interpret=pallas_interpret())[:total]
+        c = unpack_counts(words, use_pallas=True)[:total]
         if mode == "signed":
             c = 2 * c - words.shape[0]
         return tree_split_flat(c.astype(dtype), like)
@@ -253,8 +252,7 @@ def tree_unpack_counts_apply(words: jax.Array, noise: Pytree, params: Pytree,
         [l.reshape(-1).astype(jnp.float32)
          for l in jax.tree_util.tree_leaves(params)])
     out = unpack_counts_apply(words, noise_flat, base_flat, scale, a, b,
-                              use_pallas=(backend == "pallas"),
-                              interpret=pallas_interpret())
+                              use_pallas=(backend == "pallas"))
     upd = tree_split_flat(out, params)
     return jax.tree_util.tree_map(lambda p, o: o.astype(p.dtype),
                                   params, upd)

@@ -57,7 +57,7 @@ def _to_ndarray(name: str, value: Any) -> np.ndarray:
         raise TypeError(
             f"serde: buffer {name!r} is a PRNG key array — frame its "
             "jax.random.key_data(...) uint32 image instead")
-    arr = np.ascontiguousarray(np.asarray(value))
+    arr = np.asarray(value, order="C")     # keeps 0-d shapes
     if arr.dtype == object:
         raise TypeError(f"serde: buffer {name!r} is not a numeric array")
     return arr

@@ -27,10 +27,19 @@ the client mesh axis:
   3. cross-round state (EF residuals, fedpm scores) flows through the
      ``state`` pytree exactly as on the scan engine.
 
+XLA's SPMD partitioner splits the round body over the client axis, and
+it cannot partition a Mosaic kernel, so the pod program always runs the
+jnp (``ref``) kernel backend; an explicit ``backend="pallas"`` is
+refused.
+
 Because the pod program and the simulation engines share one round body,
 pod trajectories are ≡ the scan engine's at fixed seed/schedule/batches
 (``tests/test_sharded_engine.py`` asserts it to 1e-6 on 8 fake CPU
-devices) — there is no pod-only algorithm fork left to drift.
+devices) — there is no pod-only algorithm fork left to drift.  On a TPU
+mesh the partitioned convolutions round differently from the one-chip
+program, and FedMRN's mask thresholds amplify that, so there the weights
+are bitwise equal only when the client updates are (``chip_smoke.py
+--chips 4`` checks both).
 
 ``PodRoundSpec(rounds=R)`` lowers an R-round ``lax.scan`` over the round
 body — the pod-path mirror of the simulation engine's multi-round
@@ -214,6 +223,13 @@ def make_pod_round(
         raise ValueError(
             "int_mask_agg requires uniform client weights "
             "(client_weights=None)")
+    if cfg.backend == "pallas":
+        raise ValueError(
+            "make_pod_round partitions the round body with XLA's SPMD "
+            "partitioner, which cannot split a Mosaic kernel over the "
+            "client axis; leave FLConfig.backend unset (the pod program "
+            "runs the jnp kernels)")
+    cfg = dataclasses.replace(cfg, backend="ref")
     if cfg.privacy is not None:
         # the DP release is defined over the five simulation engines'
         # partial/finalize chain; the pod lowering has no parity oracle

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated on CPU with interpret=True).
+"""Pallas TPU kernels (compiled by Mosaic on TPU; interpreted on CPU).
 
 psm_mask     fused PSM masking chain (the paper's hot elementwise path)
 bitpack      1-bit mask wire-format pack/unpack

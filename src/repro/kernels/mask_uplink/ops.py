@@ -14,8 +14,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from ..psm_mask.ops import ste_clip_bwd
-from ..tiling import pad_to_multiple
+from ..tiling import LANE, pad_to_multiple
 from . import ref
 from .mask_uplink import (BLOCK_C, BLOCK_R, WORD, unpack_counts_apply_pallas,
                           unpack_counts_pallas, uplink_fused)
@@ -50,8 +51,8 @@ def _pad_all(arrs):
 def mask_uplink_fused(u: jax.Array, n: Optional[jax.Array], r_sm: jax.Array,
                       r_pm=None, progress=None, weights=None, *,
                       mode: str = "binary", wsum_values: bool = True,
-                      want_uhat: bool = False, use_pallas: bool = True,
-                      interpret: bool = True) -> UplinkOut:
+                      want_uhat: bool = False,
+                      use_pallas: bool = True) -> UplinkOut:
     """Sample → pack → count → weighted-sum, one pass over a (K, P) stack.
 
     ``mode="prob"`` reads P[m=1] directly from ``u`` (``n`` ignored);
@@ -74,10 +75,10 @@ def mask_uplink_fused(u: jax.Array, n: Optional[jax.Array], r_sm: jax.Array,
         wp = pad_to_multiple(weights.astype(jnp.float32), BLOCK_R, axis=0)
         outs = uplink_fused(up, np_, rs, rp, progress, wp, mode=mode,
                             wsum_values=wsum_values, want_uhat=want_uhat,
-                            interpret=interpret)
+                            interpret=pallas_interpret())
         words = outs[0][:K, :_packed_len(P)]
-        c = jnp.sum(outs[1], axis=0, dtype=jnp.int32)[:P]
-        wsum = jnp.sum(outs[2], axis=0)[:P]
+        c = outs[1][0, :P]
+        wsum = outs[2][0, :P]
         uhat = outs[3][:K, :P] if want_uhat else None
     if mode == "signed":
         c = 2 * c - K
@@ -89,25 +90,25 @@ def mask_uplink_fused(u: jax.Array, n: Optional[jax.Array], r_sm: jax.Array,
 # staged tree_psm/psm_ste path (shared ste_clip_bwd), everything else 0.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _uplink_ste(u, n, r_sm, r_pm, progress, weights, mode, wsum_values,
-                use_pallas, interpret):
+                use_pallas):
     out = mask_uplink_fused(u, n, r_sm, r_pm, progress, weights, mode=mode,
                             wsum_values=wsum_values, want_uhat=True,
-                            use_pallas=use_pallas, interpret=interpret)
+                            use_pallas=use_pallas)
     return out.words, out.counts, out.wsum, out.uhat
 
 
 def _uplink_ste_fwd(u, n, r_sm, r_pm, progress, weights, mode, wsum_values,
-                    use_pallas, interpret):
+                    use_pallas):
     out = _uplink_ste(u, n, r_sm, r_pm, progress, weights, mode,
-                      wsum_values, use_pallas, interpret)
+                      wsum_values, use_pallas)
     gate = (None if r_pm is None
             else r_pm < jnp.asarray(progress, jnp.float32))
     return out, (u, n, gate)
 
 
-def _uplink_ste_bwd(mode, wsum_values, use_pallas, interpret, res, cts):
+def _uplink_ste_bwd(mode, wsum_values, use_pallas, res, cts):
     u, n, gate = res
     ct_u = ste_clip_bwd(mode, u, n, gate, cts[3])   # û cotangent only
     return (ct_u, jnp.zeros_like(n), jnp.zeros_like(u),
@@ -121,8 +122,7 @@ _uplink_ste.defvjp(_uplink_ste_fwd, _uplink_ste_bwd)
 
 def mask_uplink_ste(u, n, r_sm, r_pm=None, progress=None, weights=None, *,
                     mode: str = "binary", wsum_values: bool = True,
-                    use_pallas: bool = True,
-                    interpret: bool = True) -> UplinkOut:
+                    use_pallas: bool = True) -> UplinkOut:
     """:func:`mask_uplink_fused` with û emitted and STE gradients to ``u``
     (binary/signed only — FedPM's prob mode never differentiates the
     uplink draw)."""
@@ -133,29 +133,31 @@ def mask_uplink_ste(u, n, r_sm, r_pm=None, progress=None, weights=None, *,
     progress = (None if r_pm is None
                 else jnp.asarray(progress, jnp.float32))
     return UplinkOut(*_uplink_ste(u, n, r_sm, r_pm, progress, weights, mode,
-                                  wsum_values, use_pallas, interpret))
+                                  wsum_values, use_pallas))
 
 
 # ---------------------------------------------------------------------------
 # server side
 # ---------------------------------------------------------------------------
 
-def unpack_counts(words: jax.Array, *, use_pallas: bool = True,
-                  interpret: bool = True) -> jax.Array:
+def _pad_words(words: jax.Array) -> jax.Array:
+    return pad_to_multiple(pad_to_multiple(words, LANE, axis=1),
+                           BLOCK_R, axis=0)
+
+
+def unpack_counts(words: jax.Array, *, use_pallas: bool = True) -> jax.Array:
     """(K, W) packed rows → (W·32,) int32 binary popcounts, no bit tensor
-    in HBM on the pallas route (partials reduced per word-block)."""
-    K, W = words.shape
+    in HBM on the pallas route (each word block reduced in VMEM)."""
+    W = words.shape[1]
     if not use_pallas:
         return ref.unpack_counts_ref(words)
-    wp = pad_to_multiple(pad_to_multiple(words, 128, axis=1),
-                         BLOCK_R, axis=0)
-    parts = unpack_counts_pallas(wp, interpret=interpret)
-    return jnp.sum(parts, axis=0, dtype=jnp.int32)[: W * WORD]
+    counts = unpack_counts_pallas(_pad_words(words),
+                                  interpret=pallas_interpret())
+    return counts[0, : W * WORD]
 
 
 def unpack_counts_apply(words: jax.Array, noise: jax.Array, base: jax.Array,
-                        mul, a, b, *, use_pallas: bool = True,
-                        interpret: bool = True) -> jax.Array:
+                        mul, a, b, *, use_pallas: bool = True) -> jax.Array:
     """``base + noise ⊙ (mul·(a·c + b))`` with c the per-element popcount
     of ``words`` — the Eq. (5) shared-noise server update straight from
     the aggregated wire rows.  ``noise``/``base`` are flat (P,); binary
@@ -167,8 +169,7 @@ def unpack_counts_apply(words: jax.Array, noise: jax.Array, base: jax.Array,
     if not use_pallas:
         c = ref.unpack_counts_ref(words)[:P].astype(jnp.float32)
         return base + noise * (mul * (a * c + b))
-    wp = pad_to_multiple(pad_to_multiple(words, 128, axis=1),
-                         BLOCK_R, axis=0)
+    wp = _pad_words(words)
     Wp = wp.shape[1]
     noise_p = pad_to_multiple(noise, Wp * WORD).reshape(1, -1)
     base_p = pad_to_multiple(base, Wp * WORD).reshape(1, -1)
@@ -176,5 +177,5 @@ def unpack_counts_apply(words: jax.Array, noise: jax.Array, base: jax.Array,
                          jnp.asarray(a, jnp.float32),
                          jnp.asarray(b, jnp.float32)])
     out = unpack_counts_apply_pallas(wp, noise_p, base_p, scalars,
-                                     interpret=interpret)
+                                     interpret=pallas_interpret())
     return out[0, :P]

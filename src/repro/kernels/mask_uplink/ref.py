@@ -1,9 +1,8 @@
 """Pure-jnp oracle for the fused mask-uplink kernel (same uniforms).
 
 Mirrors the kernel contract exactly — binary popcounts (the signed
-Σ(±1) = 2c − K fix lives in ``ops``), little-endian word packing — but
-returns FULL reductions instead of per-row-block partials, on the true
-unpadded (K, P) shapes.  This is also the single-program jnp fast path
+Σ(±1) = 2c − K fix lives in ``ops``), little-endian word packing — on
+the true unpadded (K, P) shapes.  This is also the single-program jnp fast path
 the ``ref`` backend runs: one fused XLA program with no pack→unpack
 round trip, versus the three-dispatch staged pipeline it replaces.
 """

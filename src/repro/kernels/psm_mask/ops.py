@@ -1,7 +1,9 @@
 """Public op: fused PSM on arbitrary-shaped tensors (+ pytree variant).
 
-``use_pallas=False`` (or non-TPU backends without interpret) falls back to
-the jnp oracle — bitwise-identical by construction (same uniforms).
+``use_pallas=False`` runs the jnp oracle — bitwise-identical by
+construction (same uniforms).  Whether the kernel is interpreted is not
+the caller's choice: ``core.backend.pallas_interpret`` decides it from
+the platform.
 """
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from ..tiling import to_lane_tiles as _to_tiles
 from .psm_mask import psm_fused
 from .ref import psm_ref
@@ -23,8 +26,7 @@ def _draw_uniforms(key: jax.Array, shape):
     return r_sm, r_pm
 
 
-def _psm_from_uniforms(u, n, r_sm, r_pm, progress, *, mode, use_pallas,
-                       interpret):
+def _psm_from_uniforms(u, n, r_sm, r_pm, progress, *, mode, use_pallas):
     if not use_pallas:
         return psm_ref(u, n, r_sm, r_pm, progress, mode=mode)
     shape = u.shape
@@ -33,18 +35,17 @@ def _psm_from_uniforms(u, n, r_sm, r_pm, progress, *, mode, use_pallas,
     rs, _ = _to_tiles(r_sm)
     rp, _ = _to_tiles(r_pm)
     uhat, mask = psm_fused(ut, nt, rs, rp, progress, mode=mode,
-                           interpret=interpret)
+                           interpret=pallas_interpret())
     return (uhat.reshape(-1)[:nelem].reshape(shape),
             mask.reshape(-1)[:nelem].reshape(shape))
 
 
 def psm_apply(u: jax.Array, n: jax.Array, key: jax.Array, progress,
-              *, mode: str = "binary", use_pallas: bool = True,
-              interpret: bool = True):
+              *, mode: str = "binary", use_pallas: bool = True):
     """PSM on a tensor of any shape → (û, mask int8) with u's shape."""
     r_sm, r_pm = _draw_uniforms(key, u.shape)
     return _psm_from_uniforms(u, n, r_sm, r_pm, progress, mode=mode,
-                              use_pallas=use_pallas, interpret=interpret)
+                              use_pallas=use_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -58,15 +59,15 @@ def psm_apply(u: jax.Array, n: jax.Array, key: jax.Array, progress,
 # making backend="pallas" gradient-identical to backend="ref".
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _psm_ste_core(u, n, r_sm, r_pm, progress, mode, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _psm_ste_core(u, n, r_sm, r_pm, progress, mode):
     uhat, _ = _psm_from_uniforms(u, n, r_sm, r_pm, progress, mode=mode,
-                                 use_pallas=True, interpret=interpret)
+                                 use_pallas=True)
     return uhat
 
 
-def _psm_ste_fwd(u, n, r_sm, r_pm, progress, mode, interpret):
-    uhat = _psm_ste_core(u, n, r_sm, r_pm, progress, mode, interpret)
+def _psm_ste_fwd(u, n, r_sm, r_pm, progress, mode):
+    uhat = _psm_ste_core(u, n, r_sm, r_pm, progress, mode)
     gate = r_pm < jnp.asarray(progress, jnp.float32)
     return uhat, (u, n, gate)
 
@@ -93,7 +94,7 @@ def ste_clip_bwd(mode, u, n, gate, g):
     return jnp.where(gate, g, zero) + clip_vjp(jnp.where(gate, zero, g))[0]
 
 
-def _psm_ste_bwd(mode, interpret, res, g):
+def _psm_ste_bwd(mode, res, g):
     u, n, gate = res
     ct_u = ste_clip_bwd(mode, u, n, gate, g)
     return (ct_u, jnp.zeros_like(n), jnp.zeros_like(g), jnp.zeros_like(g),
@@ -104,11 +105,11 @@ _psm_ste_core.defvjp(_psm_ste_fwd, _psm_ste_bwd)
 
 
 def psm_ste(u: jax.Array, n: jax.Array, key: jax.Array, progress,
-            *, mode: str = "binary", interpret: bool = True) -> jax.Array:
+            *, mode: str = "binary") -> jax.Array:
     """Differentiable PSM û via the fused kernel (STE gradients as ref)."""
     r_sm, r_pm = _draw_uniforms(key, u.shape)
     return _psm_ste_core(u, n, r_sm, r_pm,
-                         jnp.asarray(progress, jnp.float32), mode, interpret)
+                         jnp.asarray(progress, jnp.float32), mode)
 
 
 def psm_apply_tree(u: Any, n: Any, key: jax.Array, progress,

@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_R = 64
 BLOCK_C = 512
@@ -59,12 +60,13 @@ def _psm_kernel(u_ref, n_ref, r_sm_ref, r_pm_ref, prog_ref,
                                     "block_c"))
 def psm_fused(u: jax.Array, n: jax.Array, r_sm: jax.Array, r_pm: jax.Array,
               progress: jax.Array, *, mode: str = "binary",
-              interpret: bool = True, block_r: int = BLOCK_R,
+              interpret: bool, block_r: int = BLOCK_R,
               block_c: int = BLOCK_C):
     """Fused PSM over 2-D tiles. All of u/n/r_sm/r_pm shaped (R, C).
 
     Returns (û, mask int8).  ``interpret=True`` runs the kernel body in
-    Python on CPU (validation); on TPU pass interpret=False.
+    Python (the CPU validation path); the scalar ``progress`` rides in
+    SMEM.
     """
     R, C = u.shape
     br, bc = min(block_r, R), min(block_c, C)
@@ -75,9 +77,9 @@ def psm_fused(u: jax.Array, n: jax.Array, r_sm: jax.Array, r_pm: jax.Array,
         functools.partial(_psm_kernel, mode=mode),
         grid=grid,
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((R, C), u.dtype),
                    jax.ShapeDtypeStruct((R, C), jnp.int8)],
-        interpret=interpret,
+        interpret=interpret, name="psm_fused",
     )(u, n, r_sm, r_pm, prog_arr)

@@ -47,7 +47,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wkv_pallas(r, k, v, w, u, s0, *, interpret: bool = True):
+def wkv_pallas(r, k, v, w, u, s0, *, interpret: bool):
     """r,k,v,w: (B, T, H, hd); u: (H, hd); s0: (B, H, hd, hd).
 
     Returns (out (B, T, H, hd) f32, s_final (B, H, hd, hd) f32).

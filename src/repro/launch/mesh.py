@@ -8,19 +8,20 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-
-from ..compat import mesh_axis_kwargs
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Degenerate 1×1 mesh for CPU smoke runs of the sharded programs."""
-    return jax.make_mesh((1, 1), ("data", "model"), **mesh_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -18,8 +18,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import optimization_barrier
 
-from ..compat import optimization_barrier
 from ..configs.base import ModelConfig
 from ..sharding import hints
 from . import attention as attn_mod

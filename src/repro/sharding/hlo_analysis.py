@@ -116,10 +116,10 @@ class Cost:
 
 # group 2 (result shape) is matched lazily: tuple shapes can contain
 # /*index=N*/ comments (with '='!) and layout braces, so we accept anything
-# up to the first `opname(` — no parens occur inside shape strings, so the
-# first word-followed-by-( is always the op kind.
+# up to the first whitespace-preceded `opname(` — TPU layouts carry
+# tiling parens (`{1,0:T(8,128)}`) but never a space before them.
 _OP_LINE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s*([\w\-]+)\((.*)$")
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+([\w\-]+)\((.*)$")
 _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.+\{\s*$")
 
 
@@ -368,3 +368,37 @@ def analyze(hlo_text: str) -> Cost:
     total = Cost()
     total.add(_cost_of(comps[entry], comps, memo, inside_fusion=False))
     return total
+
+
+def integer_allreduces(hlo_text: str, dtype: str) -> List[Tuple[str, int]]:
+    """``(result shape, replica group size)`` of every all-reduce whose
+    reduction adds ``dtype`` values (e.g. ``"s8"``).
+
+    Matches both the typed form (CPU: ``s8[P] all-reduce``) and the form
+    XLA's TPU backend gives a narrow all-reduce: the ``dtype`` lanes packed
+    into u32 words, with a reduction computation that unpacks, adds in
+    ``dtype`` and repacks.
+    """
+    comps, _ = parse_hlo(hlo_text)
+    out = []
+    for comp in comps.values():
+        for op in comp.ops:
+            if op.kind != "all-reduce":
+                continue
+            m = re.search(r"to_apply=%?([\w.\-]+)", op.line)
+            red = comps.get(m.group(1)) if m else None
+            if red and any(o.kind == "add" and o.shape.startswith(dtype + "[")
+                           for o in red.ops):
+                out.append((op.shape.split("{")[0], _group_size(op.line)))
+    return out
+
+
+def mosaic_kernels(hlo_text: str) -> set:
+    """Names of the Mosaic (Pallas TPU) kernels in compiled HLO: the
+    ``tpu_custom_call`` ops, by instruction name less its ``.N`` suffix
+    (the ``name=`` each ``pallas_call`` is given)."""
+    comps, _ = parse_hlo(hlo_text)
+    return {re.sub(r"\.\d+$", "", op.name)
+            for comp in comps.values() for op in comp.ops
+            if op.kind == "custom-call"
+            and 'custom_call_target="tpu_custom_call"' in op.line}

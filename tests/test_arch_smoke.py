@@ -81,3 +81,17 @@ class TestArchSmoke:
         n = count_params(cfg)
         # every assigned arch is 0.3B..300B params
         assert 3e8 < n < 3e11, f"{arch}: {n/1e9:.2f}B params"
+
+
+def test_optimization_barrier_grad_and_vmap():
+    """grad + vmap through optimization_barrier, which the transformer
+    uses to pin layer weights (identity semantics)."""
+    from repro.models.transformer import optimization_barrier
+
+    def f(x):
+        return jnp.sum(optimization_barrier(x * 2.0))
+
+    x = jnp.arange(3.0)
+    assert jax.grad(f)(x) == pytest.approx([2.0, 2.0, 2.0])
+    y = jax.vmap(lambda v: optimization_barrier(v) + 1.0)(x)
+    assert y == pytest.approx([1.0, 2.0, 3.0])

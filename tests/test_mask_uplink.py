@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
     # hypothesis is a pinned requirement (requirements.txt); CI sets
@@ -68,6 +68,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=20, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1), K=st.integers(1, 9),
            mode=st.sampled_from(["binary", "signed"]))
+    @example(seed=0, K=3, mode="signed")   # cancellation in the f32 wsum
     def test_fused_equals_staged_pipeline(seed, K, mode):
         key = jax.random.key(seed)
         ku, kn, kk = jax.random.split(key, 3)
@@ -97,16 +98,19 @@ if HAVE_HYPOTHESIS:
                                       np.asarray(up_ref.counts))
         np.testing.assert_array_equal(np.asarray(up_ref.counts),
                                       np.asarray(up_pal.counts))
-        # Σ_k w_k n_k⊙m_k: fused vs staged masked-noise tensordot
+        # Σ_k w_k n_k⊙m_k: fused vs staged masked-noise tensordot.  The
+        # three routes sum K f32 terms in different orders; where the
+        # terms cancel the result is near zero, so bound the difference
+        # by the summation error, 2·K·eps·Σ_k |w_k n_k|, not by rtol
         hat = jax.tree_util.tree_map(
             lambda nl, ml: nl * ml.astype(nl.dtype), n, masks)
         wsum_staged = jnp.tensordot(w, jnp.asarray(_flat(hat, K)), axes=1)
-        np.testing.assert_allclose(np.asarray(up_ref.wsum),
-                                   np.asarray(wsum_staged),
-                                   rtol=2e-6, atol=1e-12)
-        np.testing.assert_allclose(np.asarray(up_pal.wsum),
-                                   np.asarray(up_ref.wsum),
-                                   rtol=2e-6, atol=1e-12)
+        tol = (2 * K * np.finfo(np.float32).eps
+               * np.abs(np.asarray(w)[:, None] * _flat(n, K)).sum(axis=0))
+        for got, want in ((up_ref.wsum, wsum_staged),
+                          (up_pal.wsum, up_ref.wsum)):
+            err = np.abs(np.asarray(got) - np.asarray(want))
+            assert np.all(err <= tol), (err - tol).max()
 
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1), K=st.integers(1, 6))
@@ -150,7 +154,7 @@ def test_ste_gradients_bitwise(mode, gated):
     if gated:
         def f_staged(uu):
             uh = _psm_ste_core(uu, n, r_sm, r_pm, jnp.float32(prog),
-                               mode, True)
+                               mode)
             return jnp.sum(uh * cot)
         g_staged = jax.grad(f_staged)(u)
         np.testing.assert_array_equal(np.asarray(g_fused),
@@ -420,8 +424,7 @@ def test_compiled_kernel_matches_oracle():
     r = jax.random.uniform(ks, (K, P))
     w = jnp.ones((K,))
     ref = mops.mask_uplink_fused(u, n, r, None, None, w, use_pallas=False)
-    pal = mops.mask_uplink_fused(u, n, r, None, None, w, use_pallas=True,
-                                 interpret=False)
+    pal = mops.mask_uplink_fused(u, n, r, None, None, w, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(ref.words),
                                   np.asarray(pal.words))
     np.testing.assert_array_equal(np.asarray(ref.counts),

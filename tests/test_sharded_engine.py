@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.data import (make_federated_dataset, make_image_task,
                         make_partition)
@@ -39,11 +40,12 @@ NDEV = jax.device_count()
 def _pod_mesh():
     """A (data, model) mesh over everything available: (4, 2) on the 8
     fake CI devices, (1, 1) degenerate inside the single-device suite."""
+    auto = {"axis_types": (AxisType.Auto,) * 2}
     if NDEV >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"))
+        return jax.make_mesh((4, 2), ("data", "model"), **auto)
     if NDEV >= 2:
-        return jax.make_mesh((NDEV, 1), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+        return jax.make_mesh((NDEV, 1), ("data", "model"), **auto)
+    return jax.make_mesh((1, 1), ("data", "model"), **auto)
 
 
 def _setup(algorithm, rounds=3, **cfg_kw):
@@ -407,3 +409,12 @@ def test_sharded_sweep_argument_validation():
         exp.sweep(seeds=2, sharding="pods")
     with pytest.raises(ValueError, match="devices"):
         exp.sweep(seeds=2, devices=2)         # devices without sharding=
+
+
+def test_pod_round_refuses_pallas_backend():
+    """XLA cannot partition a Mosaic kernel over the client axis, so the
+    pod program lowers the jnp kernels; an explicit pallas backend is
+    refused instead of failing to compile on a TPU mesh."""
+    loss_fn, params, ds, cfg = _setup("fedmrn", backend="pallas")
+    with pytest.raises(ValueError, match="Mosaic"):
+        _pod_program(cfg, loss_fn, params, ds)

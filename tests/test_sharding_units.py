@@ -111,3 +111,9 @@ class TestShardingRules:
             # leaf count parity
             assert len(jax.tree_util.tree_leaves(shard)) == len(
                 jax.tree_util.tree_leaves(specs))
+
+
+def test_meshes_mark_every_axis_auto():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    assert make_host_mesh().axis_types == (AxisType.Auto,) * 2
