@@ -36,7 +36,8 @@ def make_eval_program(
 
     ``apply_fn(params, x_batch) -> (B, n_classes) logits``.  The returned
     program is pure and jit/scan/cond-safe; accuracy is the exact mean of
-    argmax-correctness over the ``len(y)`` true examples.
+    argmax-correctness over the ``len(y)`` true examples.  Its device
+    instructions carry the ``fed.eval`` name scope.
     """
     n = int(y.shape[0])
     if n == 0:
@@ -56,8 +57,10 @@ def make_eval_program(
             hits = ((pred == yi) & vi).astype(jnp.float32)
             return correct + jnp.sum(hits), None
 
-        correct, _ = jax.lax.scan(body, jnp.float32(0.0), (xb, yb, valid))
-        return correct / n
+        with jax.named_scope("fed.eval"):
+            correct, _ = jax.lax.scan(body, jnp.float32(0.0),
+                                      (xb, yb, valid))
+            return correct / n
 
     return program
 
@@ -76,6 +79,7 @@ def make_negloss_eval_program(
     batch = jax.tree_util.tree_map(jnp.asarray, batch)
 
     def program(params: Pytree) -> jax.Array:
-        return -jnp.float32(loss_fn(params, batch))
+        with jax.named_scope("fed.eval"):
+            return -jnp.float32(loss_fn(params, batch))
 
     return program

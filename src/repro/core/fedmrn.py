@@ -105,6 +105,10 @@ def psm_local_train(
     The shared local-training body of every FedMRN round program: the
     simulation engine vmaps it over a stacked client axis, the pod program
     runs it per mesh-client slice.  Returns (u_final, per-step losses).
+
+    Its device instructions carry the ``fed.local_train`` name scope, and
+    the masking and the mix with ``w_global`` carry ``fed.psm``, in the
+    forward pass and (as ``transpose(jvp(fed.psm))``) in the backward.
     """
     num_steps = jax.tree_util.tree_leaves(batches)[0].shape[0]
     if u0 is None:
@@ -116,16 +120,19 @@ def psm_local_train(
         k = jax.random.fold_in(train_key, tau)
 
         def fwd(u_):
-            u_hat = _masked_update(u_, noise, k, progress=progress, cfg=cfg)
-            return loss_fn(jax.tree_util.tree_map(_mix_add, w_global, u_hat),
-                           batch)
+            with jax.named_scope("fed.psm"):
+                u_hat = _masked_update(u_, noise, k, progress=progress,
+                                       cfg=cfg)
+                w_hat = jax.tree_util.tree_map(_mix_add, w_global, u_hat)
+            return loss_fn(w_hat, batch)
 
         loss, grads = jax.value_and_grad(fwd)(u)
         u = jax.tree_util.tree_map(lambda a, g: a - cfg.lr * g, u, grads)
         return u, loss
 
-    taus = jnp.arange(num_steps, dtype=jnp.float32)
-    return jax.lax.scan(step, u0, (taus, batches))
+    with jax.named_scope("fed.local_train"):
+        taus = jnp.arange(num_steps, dtype=jnp.float32)
+        return jax.lax.scan(step, u0, (taus, batches))
 
 
 def sample_final_mask(
@@ -195,15 +202,19 @@ def sgd_local_update(
     *,
     lr: float,
 ) -> Tuple[Pytree, jax.Array]:
-    """Vanilla local SGD; returns (u = w_local − w_global, per-step losses)."""
+    """Vanilla local SGD; returns (u = w_local − w_global, per-step losses).
+
+    Its device instructions carry the ``fed.local_train`` name scope.
+    """
 
     def step(w, batch):
         loss, grads = jax.value_and_grad(loss_fn)(w, batch)
         w = jax.tree_util.tree_map(lambda a, g: a - lr * g, w, grads)
         return w, loss
 
-    w_final, losses = jax.lax.scan(step, w_global, batches)
-    u = jax.tree_util.tree_map(jnp.subtract, w_final, w_global)
+    with jax.named_scope("fed.local_train"):
+        w_final, losses = jax.lax.scan(step, w_global, batches)
+        u = jax.tree_util.tree_map(jnp.subtract, w_final, w_global)
     return u, losses
 
 

@@ -65,13 +65,16 @@ def gen_noise(key: jax.Array, tree: Pytree, cfg: NoiseConfig) -> Pytree:
 
     Each leaf gets an independent stream via ``fold_in(key, leaf_index)`` so
     the result is invariant to leaf sizes (no global offset bookkeeping) and
-    identical between client and server.
+    identical between client and server.  Its device instructions carry
+    the ``fed.noise`` name scope wherever the draw runs (client or server).
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     noises = []
-    for i, leaf in enumerate(leaves):
-        lk = jax.random.fold_in(key, i)
-        noises.append(_leaf_noise(lk, jnp.shape(leaf), cfg).astype(leaf.dtype))
+    with jax.named_scope("fed.noise"):
+        for i, leaf in enumerate(leaves):
+            lk = jax.random.fold_in(key, i)
+            noises.append(
+                _leaf_noise(lk, jnp.shape(leaf), cfg).astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(treedef, noises)
 
 

@@ -74,8 +74,9 @@ class FederatedDataset:
         ``picked`` is a (K,) int32 array; ``round_idx`` may be traced
         (it is the scan counter inside the experiment program).
         """
-        return jax.vmap(lambda c: self.client_batch(
-            round_idx, c, steps=steps, batch=batch))(picked)
+        with jax.named_scope("fed.gather"):
+            return jax.vmap(lambda c: self.client_batch(
+                round_idx, c, steps=steps, batch=batch))(picked)
 
     # ---- legacy host adapter -----------------------------------------
 

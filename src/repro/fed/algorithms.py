@@ -302,6 +302,7 @@ def fedpm_local(loss_fn, w_init, scores, batches, *, lr, key, sample=True):
     scores — the fused round body then hands ``sigmoid(scores)`` to
     ``MaskCodec.uplink_stacked``, which performs the SAME Bernoulli draw
     (identical key/uniform streams) inside the fused mask-uplink kernel.
+    Its device instructions carry the ``fed.local_train`` name scope.
     """
 
     def masked_params(s, k):
@@ -327,18 +328,19 @@ def fedpm_local(loss_fn, w_init, scores, batches, *, lr, key, sample=True):
         return s, loss
 
     n = jax.tree_util.tree_leaves(batches)[0].shape[0]
-    s_final, losses = jax.lax.scan(step, scores,
-                                   (jnp.arange(n), batches))
-    if not sample:
-        return s_final, losses
-    # uplink: Bernoulli-sampled masks, one independent draw per leaf
-    # (folding the leaf index keeps same-shaped leaves decorrelated)
-    leaves, treedef = jax.tree_util.tree_flatten(s_final)
-    mask_key = jax.random.fold_in(key, n + 1)
-    masks = jax.tree_util.tree_unflatten(treedef, [
-        jax.random.bernoulli(jax.random.fold_in(mask_key, i),
-                             jax.nn.sigmoid(sl)).astype(jnp.float32)
-        for i, sl in enumerate(leaves)])
+    with jax.named_scope("fed.local_train"):
+        s_final, losses = jax.lax.scan(step, scores,
+                                       (jnp.arange(n), batches))
+        if not sample:
+            return s_final, losses
+        # uplink: Bernoulli-sampled masks, one independent draw per leaf
+        # (folding the leaf index keeps same-shaped leaves decorrelated)
+        leaves, treedef = jax.tree_util.tree_flatten(s_final)
+        mask_key = jax.random.fold_in(key, n + 1)
+        masks = jax.tree_util.tree_unflatten(treedef, [
+            jax.random.bernoulli(jax.random.fold_in(mask_key, i),
+                                 jax.nn.sigmoid(sl)).astype(jnp.float32)
+            for i, sl in enumerate(leaves)])
     return masks, losses
 
 
@@ -413,17 +415,23 @@ def _fedmrn_body(loss_fn, cfg: FLConfig, params: Pytree) -> RoundBody:
             # ---- uplink + server sum in ONE fused pass (Eq. 5) ---------
             u_stack, seed_keys, mask_keys, losses = jax.vmap(per_client)(
                 batches, picked, r0)
-            msg, agg = codec.uplink_stacked(u_stack, seed_keys, mask_keys,
-                                            weights, round_idx=round_idx)
-            new_w = jax.tree_util.tree_map(mix_add, w, agg)
+            with jax.named_scope("fed.uplink"):
+                msg, agg = codec.uplink_stacked(u_stack, seed_keys,
+                                                mask_keys, weights,
+                                                round_idx=round_idx)
+            with jax.named_scope("fed.server"):
+                new_w = jax.tree_util.tree_map(mix_add, w, agg)
             return new_w, state, losses, codec.round_bits(msg)
 
         masks, seed_keys, losses, residuals = jax.vmap(per_client)(
             batches, picked, r0)
         # ---- uplink: (packed masks, seeds) encoded in one kernel launch
-        msg = codec.encode_stacked({"mask": masks, "seed": seed_keys})
+        with jax.named_scope("fed.uplink"):
+            msg = codec.encode_stacked({"mask": masks, "seed": seed_keys})
         # ---- server: the codec is the decode boundary — Eq. (5) --------
-        new_w = codec.aggregate_apply(msg, weights, w, round_idx=round_idx)
+        with jax.named_scope("fed.server"):
+            new_w = codec.aggregate_apply(msg, weights, w,
+                                          round_idx=round_idx)
 
         new_state = state
         if ef:
@@ -565,9 +573,11 @@ def _fedavg_family_body(compressor_name: Optional[str]):
                 # chain the in-body roundtrip used (ckeys dead-code
                 # otherwise)
                 payload["key"] = ckeys
-            msg = codec.encode_stacked(payload)
-            agg = codec.aggregate(msg, weights)
-            new_w = jax.tree_util.tree_map(mix_add, w, agg)
+            with jax.named_scope("fed.uplink"):
+                msg = codec.encode_stacked(payload)
+                agg = codec.aggregate(msg, weights)
+            with jax.named_scope("fed.server"):
+                new_w = jax.tree_util.tree_map(mix_add, w, agg)
             return new_w, state, losses, codec.round_bits(msg)
 
         return round_fn
@@ -676,9 +686,10 @@ def _fedpm_body(loss_fn, cfg: FLConfig, params: Pytree) -> RoundBody:
         # NaN the logit below.  A zero weight marks a DROPPED client
         # (availability trace) and casts no vote.
         votes = (weights > 0).astype(jnp.float32)
-        msg, m_sum = codec.uplink_stacked(probs_k, None, mask_keys,
-                                          votes, probs=True,
-                                          round_idx=round_idx)
+        with jax.named_scope("fed.uplink"):
+            msg, m_sum = codec.uplink_stacked(probs_k, None, mask_keys,
+                                              votes, probs=True,
+                                              round_idx=round_idx)
         nv = jnp.sum(votes)
         # Beta(1,1)-posterior (Laplace-smoothed) mask-frequency estimate,
         # accumulated in f32 regardless of param dtype.  The raw nv-client
@@ -687,10 +698,11 @@ def _fedpm_body(loss_fn, cfg: FLConfig, params: Pytree) -> RoundBody:
         # training freezes.  Smoothing bounds scores to |logit| ≤ ln(nv+1);
         # under privacy the noisy sum is additionally clamped back into
         # the noiseless release's span before the logit (NaN guard).
-        probs, new_scores = fedpm_posterior(m_sum, nv,
-                                            clamp=cfg.privacy is not None)
-        new_w = jax.tree_util.tree_map(
-            lambda wf, pr: wf * (pr > 0.5), w_frozen, probs)
+        with jax.named_scope("fed.server"):
+            probs, new_scores = fedpm_posterior(
+                m_sum, nv, clamp=cfg.privacy is not None)
+            new_w = jax.tree_util.tree_map(
+                lambda wf, pr: wf * (pr > 0.5), w_frozen, probs)
         return new_w, {"scores": new_scores}, losses, codec.round_bits(msg)
 
     return round_fn
@@ -754,10 +766,12 @@ def _fedsparsify_body(loss_fn, cfg: FLConfig, params: Pytree) -> RoundBody:
 
         w_locals, losses = jax.vmap(per_client)(batches, picked)
         # the pruned local WEIGHTS are the payload: top-k values+indices
-        msg = codec.encode_stacked({"value": w_locals})
-        new_w = codec.aggregate(msg, weights)
-        new_w = jax.tree_util.tree_map(lambda p, a: a.astype(p.dtype),
-                                       w, new_w)
+        with jax.named_scope("fed.uplink"):
+            msg = codec.encode_stacked({"value": w_locals})
+            agg = codec.aggregate(msg, weights)
+        with jax.named_scope("fed.server"):
+            new_w = jax.tree_util.tree_map(lambda p, a: a.astype(p.dtype),
+                                           w, agg)
         return new_w, state, losses, codec.round_bits(msg)
 
     return round_fn

@@ -34,6 +34,14 @@ Example::
     sweep = exp.sweep(seeds=8)                # one vmapped program
     mean, std = sweep.point.mean_std()
 
+``run()`` names its host phases for the profiler with
+``jax.profiler.TraceAnnotation`` spans, which record nothing unless a
+profiler trace is running: ``fed.run.schedule`` (client schedule and its
+copy to the device) and ``fed.run.dispatch`` (the chunk calls; at the
+first call also trace, lower and compile) on the scan engine, and on
+every engine ``fed.run.readback`` (the one device-to-host read of the
+round metrics) and ``fed.run.result`` (building the :class:`RunResult`).
+
 Compiled scan/sweep programs are cached on the :class:`Experiment`
 (keyed by config with the seed normalised out — the seed is a *traced*
 argument), so repeated scan ``run()``/``sweep()`` calls and host-loop
@@ -54,6 +62,7 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import tree_num_params
 from ..core.comm import CommRecord
@@ -539,28 +548,30 @@ class Experiment:
         run_chunk, state0, metrics0 = self._program("seeded", cfg)
         chunk = cfg.rounds if chunk is None else max(1, int(chunk))
         chunk = min(chunk, cfg.rounds)
-        schedule = make_client_schedule(cfg)
-        schedule, valid, participation = self._degrade_schedule(
-            cfg, "scan", schedule, self._availability(cfg))
-        sched_dev = jnp.asarray(schedule, jnp.int32)
-        valid_dev = None if valid is None else jnp.asarray(valid,
-                                                           jnp.float32)
-        seed_dev = jnp.int32(cfg.seed)
+        with TraceAnnotation("fed.run.schedule"):
+            schedule = make_client_schedule(cfg)
+            schedule, valid, participation = self._degrade_schedule(
+                cfg, "scan", schedule, self._availability(cfg))
+            sched_dev = jnp.asarray(schedule, jnp.int32)
+            valid_dev = None if valid is None else jnp.asarray(valid,
+                                                               jnp.float32)
+            seed_dev = jnp.int32(cfg.seed)
         w, state, metrics = self.spec.params, state0, metrics0
         t0 = time.time()
         dispatches = 0
-        for r0 in range(0, cfg.rounds, chunk):
-            n = min(chunk, cfg.rounds - r0)
-            if valid_dev is None:
-                w, state, metrics = run_chunk(
-                    seed_dev, w, state, metrics, jnp.int32(r0),
-                    sched_dev[r0:r0 + n], n_rounds=n)
-            else:
-                w, state, metrics = run_chunk(
-                    seed_dev, w, state, metrics, jnp.int32(r0),
-                    sched_dev[r0:r0 + n], valid_dev[r0:r0 + n],
-                    n_rounds=n)
-            dispatches += 1
+        with TraceAnnotation("fed.run.dispatch"):
+            for r0 in range(0, cfg.rounds, chunk):
+                n = min(chunk, cfg.rounds - r0)
+                if valid_dev is None:
+                    w, state, metrics = run_chunk(
+                        seed_dev, w, state, metrics, jnp.int32(r0),
+                        sched_dev[r0:r0 + n], n_rounds=n)
+                else:
+                    w, state, metrics = run_chunk(
+                        seed_dev, w, state, metrics, jnp.int32(r0),
+                        sched_dev[r0:r0 + n], valid_dev[r0:r0 + n],
+                        n_rounds=n)
+                dispatches += 1
         # the ONLY device→host reads of the whole experiment
         result = self._result_from_metrics(
             cfg, "scan", metrics, schedule, dispatches, time.time() - t0,
@@ -671,25 +682,27 @@ class Experiment:
     def _result_from_metrics(self, cfg, engine, metrics, schedule,
                              dispatches, wall_s,
                              participation=None) -> RunResult:
-        loss = np.asarray(metrics["loss"])
-        acc = np.asarray(metrics["acc"])
-        bits = np.asarray(metrics["uplink_bits"])
-        rounds = eval_round_indices(cfg, self.spec.eval_every)
-        if participation is None:
-            participation = [cfg.clients_per_round] * cfg.rounds
-        dp_eps, dp_delta = dp_epsilon_schedule(
-            cfg, participation, tree_num_params(self.spec.params))
-        return RunResult(
-            algorithm=cfg.algorithm, engine=engine, config=cfg,
-            seed=cfg.seed, eval_rounds=tuple(rounds),
-            acc=tuple(float(acc[r]) for r in rounds),
-            local_loss=tuple(float(x) for x in loss),
-            uplink_bits_round=tuple(float(b) for b in bits),
-            uplink_bits_per_client=uplink_bits(cfg, self.spec.params),
-            num_params=tree_num_params(self.spec.params),
-            schedule=schedule, num_dispatches=dispatches, wall_s=wall_s,
-            participation_round=tuple(int(p) for p in participation),
-            dp_epsilon=dp_eps, dp_delta=dp_delta)
+        with TraceAnnotation("fed.run.readback"):
+            host = jax.device_get(metrics)
+        with TraceAnnotation("fed.run.result"):
+            loss, acc, bits = host["loss"], host["acc"], host["uplink_bits"]
+            rounds = eval_round_indices(cfg, self.spec.eval_every)
+            if participation is None:
+                participation = [cfg.clients_per_round] * cfg.rounds
+            dp_eps, dp_delta = dp_epsilon_schedule(
+                cfg, participation, tree_num_params(self.spec.params))
+            return RunResult(
+                algorithm=cfg.algorithm, engine=engine, config=cfg,
+                seed=cfg.seed, eval_rounds=tuple(rounds),
+                acc=tuple(float(acc[r]) for r in rounds),
+                local_loss=tuple(float(x) for x in loss),
+                uplink_bits_round=tuple(float(b) for b in bits),
+                uplink_bits_per_client=uplink_bits(cfg, self.spec.params),
+                num_params=tree_num_params(self.spec.params),
+                schedule=schedule, num_dispatches=dispatches,
+                wall_s=wall_s,
+                participation_round=tuple(int(p) for p in participation),
+                dp_epsilon=dp_eps, dp_delta=dp_delta)
 
     def _run_host_loop(self, cfg: FLConfig, engine: str) -> RunResult:
         from .simulation import _run_batched          # no import cycle:
